@@ -1,6 +1,8 @@
-"""Kernel micro-bench: µs/call of each Pallas kernel (interpret on CPU —
-informational; the TPU numbers come from the roofline dry-run) vs its jnp
-reference."""
+"""Kernel micro-bench: µs/call of each kernel against its jnp reference, on
+whatever backend JAX runs. On a CPU the Pallas kernels run in interpret mode
+and `bitvec_rank` on XLA:CPU, so these numbers rank CPU hypotheses only;
+they are never device numbers (device timings come from runs on the chip).
+"""
 from __future__ import annotations
 
 import time
@@ -14,29 +16,28 @@ from repro.kernels.segment_matmul import build_csr_blocks
 
 
 def _k2_batched_row_bench(rng, n_rows=256, iters=3):
-    """Time one batched multi-row k²-tree expansion with the bitvector rank
-    routed through the Pallas kernel (interpret off-TPU) vs pure numpy."""
-    from repro.core.succinct import K2Tree, set_rank_backend
+    """Time one batched multi-row k²-tree expansion with its levels placed
+    for the device rank (XLA on this backend) vs the host numpy rank."""
+    from repro.core.succinct import K2Tree
+    from repro.core.succinct.device_rank import DeviceLevels
 
     n = m = 2048
     r = rng.integers(0, n, 20000)
     c = rng.integers(0, m, 20000)
-    tree = K2Tree(r, c, n, m)
+    host = K2Tree(r, c, n, m)
+    placed = K2Tree(r, c, n, m)
+    if placed.device is None:
+        placed.device = DeviceLevels(placed.levels)
     qs = rng.integers(0, n, n_rows).astype(np.int64)
 
-    def run_once():
-        return tree.rows_many(qs)
-
     timings = {}
-    for backend in ("pallas", "numpy"):
-        old = set_rank_backend(backend)
-        run_once()  # warmup (compilation / caches)
+    for name, tree in (("device", placed), ("numpy", host)):
+        tree.rows_many(qs)  # warmup (compilation / caches)
         t0 = time.perf_counter()
         for _ in range(iters):
-            run_once()
-        timings[backend] = (time.perf_counter() - t0) / iters * 1e6
-        set_rank_backend(old)
-    return (f"k2_rows_batched_{n_rows}r", timings["pallas"], timings["numpy"])
+            tree.rows_many(qs)
+        timings[name] = (time.perf_counter() - t0) / iters * 1e6
+    return (f"k2_rows_batched_{n_rows}r", timings["device"], timings["numpy"])
 
 
 def _time(fn, *args, iters=5):
@@ -94,14 +95,14 @@ def run(quiet=False):
                  _time(jax.jit(ref.bitvec_rank_ref), words, ranks, pos_odd)))
 
     # batched k²-tree multi-row traversal (the query-engine hot loop): one
-    # level-synchronous sweep for 256 rows, rank routed pallas vs numpy
+    # level-synchronous sweep for 256 rows, device-placed rank vs numpy
     rows.append(_k2_batched_row_bench(rng, n_rows=256))
 
     out = []
     for name, k_us, r_us in rows:
-        out.append({"kernel": name, "pallas_interpret_us": k_us, "jnp_ref_us": r_us})
+        out.append({"kernel": name, "kernel_us": k_us, "jnp_ref_us": r_us})
         if not quiet:
-            print(f"kern {name:<22} pallas(interp)={k_us:9.1f}us  jnp_ref={r_us:9.1f}us")
+            print(f"kern {name:<22} kernel={k_us:9.1f}us  ref={r_us:9.1f}us")
     return out
 
 

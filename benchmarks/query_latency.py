@@ -959,4 +959,7 @@ def _finalize_throughput(bench: dict, n_queries: int) -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

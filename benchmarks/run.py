@@ -308,7 +308,7 @@ def main(smoke: bool = False, check: bool = False,
     abl = ablations.run()
     print("\n== compression throughput ==")
     speed = compression_speed.run(sizes=(2000,) if smoke else (2000, 8000, 32000))
-    print("\n== kernel micro-bench (CPU interpret) ==")
+    print("\n== kernel micro-bench (CPU: interpret / XLA:CPU, not device numbers) ==")
     kerns = kernels_bench.run()
 
     print("\n== CSV ==")
@@ -393,7 +393,7 @@ def main(smoke: bool = False, check: bool = False,
     for row in speed:
         print(f"speed/E{row['edges']},{row['edges_per_s']:.0f},edges_per_s")
     for row in kerns:
-        print(f"kernel/{row['kernel']},{row['pallas_interpret_us']:.1f},us_per_call")
+        print(f"kernel/{row['kernel']},{row['kernel_us']:.1f},us_per_call")
 
     # roofline summary if the dry-run has produced results (skipped in smoke:
     # it only reports on artifacts a TPU dry-run would have left behind)
@@ -444,5 +444,8 @@ if __name__ == "__main__":
     args = parser.parse_args()
     if (args.check or args.update_baseline) and not args.smoke:
         parser.error("--check/--update-baseline require --smoke")
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main(smoke=args.smoke, check=args.check, update=args.update_baseline,
          tolerance=args.tolerance)

@@ -416,6 +416,9 @@ if __name__ == "__main__":
                         help=f"output path (default {BENCH_JSON})")
     parser.add_argument("--quiet", action="store_true")
     args = parser.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         bench = run_smoke(quiet=args.quiet)
         print(json.dumps(bench["smoke_signals"], indent=2))

@@ -1,14 +1,13 @@
 """Succinct data structures used by the ITR encoder/decoder and query engine.
 
 All structures report `size_in_bytes()` so compression benchmarks account the
-true serialized footprint, and expose numpy-side query paths (the hot batched
-paths additionally have Pallas kernels in `repro.kernels`).
+true serialized footprint, and expose numpy-side query paths. On a TPU the
+k²-tree's levels also live on the device, where its batched rank runs
+(`repro.core.succinct.device_rank`).
 """
 from repro.core.succinct.bitvector import (
     BitVector,
-    get_rank_backend,
     pack_bits,
-    set_rank_backend,
     unpack_bits,
 )
 from repro.core.succinct.elias_fano import EliasFano
@@ -22,8 +21,6 @@ from repro.core.succinct.k2tree import K2Tree
 
 __all__ = [
     "BitVector",
-    "get_rank_backend",
-    "set_rank_backend",
     "pack_bits",
     "unpack_bits",
     "EliasFano",

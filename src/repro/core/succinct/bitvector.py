@@ -6,47 +6,17 @@ accounted separately so size reports can include or exclude the index);
 select is O(log W) via searchsorted over the prefix array.
 
 Construction is fully vectorized numpy; queries have both scalar and batched
-(numpy array) entry points. Batched `rank1` can additionally be routed
-through the Pallas kernel (`repro.kernels.bitvec_rank`) — the TPU query
-path — via :func:`set_rank_backend`; numpy remains the fallback (and the
-parity oracle for the kernel: `tests/test_succinct.py`).
+(numpy array) entry points, all on the host. `rank1` here is the parity
+oracle for the device rank of the k²-tree levels
+(`repro.core.succinct.device_rank`, `tests/test_succinct.py`).
 """
 from __future__ import annotations
-
-import os
-import warnings
 
 import numpy as np
 
 _M1 = np.uint32(0x55555555)
 _M2 = np.uint32(0x33333333)
 _M4 = np.uint32(0x0F0F0F0F)
-
-# rank backend: "numpy" (default) or "pallas" (Pallas kernel; interpret mode
-# off-TPU). Batches below _PALLAS_MIN_BATCH always take the numpy path —
-# kernel dispatch overhead dominates tiny queries. Once the kernel fails
-# (missing jax, lowering error) the process sticks to numpy (_PALLAS_BROKEN).
-_RANK_BACKEND = os.environ.get("ITR_RANK_BACKEND", "numpy")
-if _RANK_BACKEND not in ("numpy", "pallas"):
-    warnings.warn(f"ITR_RANK_BACKEND={_RANK_BACKEND!r} unknown; using numpy")
-    _RANK_BACKEND = "numpy"
-_PALLAS_MIN_BATCH = 32
-_PALLAS_BROKEN = False
-
-
-def set_rank_backend(name: str) -> str:
-    """Select the batched-rank backend ("numpy" | "pallas"); returns the old one."""
-    global _RANK_BACKEND, _PALLAS_BROKEN
-    if name not in ("numpy", "pallas"):
-        raise ValueError(f"unknown rank backend {name!r}")
-    old, _RANK_BACKEND = _RANK_BACKEND, name
-    if name == "pallas":
-        _PALLAS_BROKEN = False  # explicit re-opt-in retries the kernel once
-    return old
-
-
-def get_rank_backend() -> str:
-    return _RANK_BACKEND
 
 
 def popcount32(words: np.ndarray) -> np.ndarray:
@@ -89,7 +59,6 @@ class BitVector:
         # word_ranks[w] = number of 1s strictly before word w
         self.word_ranks = np.concatenate([[0], np.cumsum(pc)]).astype(np.int64)
         self.n_ones = int(self.word_ranks[-1])
-        self._jax_words = None  # lazy device copies for the Pallas rank path
 
     @classmethod
     def from_positions(cls, positions: np.ndarray, n: int) -> "BitVector":
@@ -112,7 +81,6 @@ class BitVector:
         pc = popcount32(self.words)
         self.word_ranks = np.concatenate([[0], np.cumsum(pc)]).astype(np.int64)
         self.n_ones = int(self.word_ranks[-1])
-        self._jax_words = None
         return self
 
     def __len__(self) -> int:
@@ -125,14 +93,6 @@ class BitVector:
     def rank1(self, i) -> np.ndarray:
         """Number of set bits in [0, i). Accepts scalars or arrays; i in [0, n]."""
         i = np.asarray(i, dtype=np.int64)
-        if (_RANK_BACKEND == "pallas" and not _PALLAS_BROKEN
-                and i.ndim == 1 and i.size >= _PALLAS_MIN_BATCH):
-            out = self._rank1_pallas(i)
-            if out is not None:
-                return out
-        return self._rank1_numpy(i)
-
-    def _rank1_numpy(self, i: np.ndarray) -> np.ndarray:
         w = i >> 5
         rem = (i & 31).astype(np.uint32)
         mask = np.where(rem == 0, np.uint32(0), (np.uint32(1) << rem) - np.uint32(1))
@@ -140,31 +100,6 @@ class BitVector:
         wordvals = self.words[np.minimum(w, len(self.words) - 1)] if len(self.words) else np.zeros_like(w, dtype=np.uint32)
         partial = popcount32(np.where(w < len(self.words), wordvals & mask, np.uint32(0)))
         return self.word_ranks[np.minimum(w, len(self.word_ranks) - 1)] + partial
-
-    def _rank1_pallas(self, i: np.ndarray) -> np.ndarray | None:
-        """Batched rank via the Pallas kernel; None on failure (numpy fallback).
-
-        Words are padded with one trailing zero word so i == n (one past the
-        last bit) indexes in-bounds; the exclusive prefix `word_ranks` already
-        has W+1 entries and lines up with the padded words.
-        """
-        global _PALLAS_BROKEN
-        try:
-            import jax.numpy as jnp
-
-            from repro.kernels.ops import bitvec_rank as _kernel_rank
-
-            if self._jax_words is None:
-                self._jax_words = jnp.asarray(
-                    np.concatenate([self.words, np.zeros(1, np.uint32)]))
-                self._jax_ranks = jnp.asarray(self.word_ranks.astype(np.int32))
-            out = _kernel_rank(self._jax_words, self._jax_ranks,
-                               jnp.asarray(i.astype(np.int32)))
-            return np.asarray(out).astype(np.int64)
-        except Exception as e:  # missing jax backend, lowering failure, ...
-            _PALLAS_BROKEN = True  # don't re-pay the failed attempt per call
-            warnings.warn(f"pallas rank backend unavailable ({e!r}); using numpy")
-            return None
 
     def rank0(self, i) -> np.ndarray:
         i = np.asarray(i, dtype=np.int64)

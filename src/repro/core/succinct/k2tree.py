@@ -10,11 +10,19 @@ BitVector per level (identical total bit count, plus one pointer per level);
 child block of the j-th set bit of level t is block j of level t+1. This
 keeps construction fully vectorized (digit-radix sort per level) and row/
 column expansion a simple per-level frontier sweep.
+
+On a TPU backend the levels are also laid out on the device once, when the
+tree is built or loaded, and the descent's batched rank runs there
+(`repro.core.succinct.device_rank`); `rank_calls` counts rank calls per
+call site and side.
 """
 from __future__ import annotations
 
+from collections import Counter
+
 import numpy as np
 
+from repro.core.succinct import device_rank
 from repro.core.succinct.bitvector import BitVector
 
 
@@ -35,6 +43,7 @@ class K2Tree:
         self.n_points = 0
         self.levels: list[BitVector] = []
         self._build(rows, cols)
+        self._place()
 
     @classmethod
     def from_levels(cls, n_rows: int, n_cols: int, k: int, h: int,
@@ -42,8 +51,6 @@ class K2Tree:
         """Reconstruct from persisted per-level bitvector words (the
         snapshot load path): no COO radix build, only rank-index
         recomputation inside each :meth:`BitVector.from_words`."""
-        from repro.core.succinct.bitvector import BitVector as _BV
-
         self = cls.__new__(cls)
         self.n_rows, self.n_cols, self.k = int(n_rows), int(n_cols), int(k)
         self.h = int(h)
@@ -53,9 +60,24 @@ class K2Tree:
                                                and n_points == 0):
             raise ValueError(
                 f"{len(level_words)} levels for a height-{self.h} k2-tree")
-        self.levels = [_BV.from_words(w, int(nb))
+        self.levels = [BitVector.from_words(w, int(nb))
                        for w, nb in zip(level_words, level_bits)]
+        self._place()
         return self
+
+    def _place(self):
+        """Upload the levels once, where the platform runs rank on the device."""
+        self.device = device_rank.DeviceLevels(self.levels) \
+            if device_rank.enabled() else None
+        #: rank calls by (call site, "device" | "host")
+        self.rank_calls: Counter = Counter()
+
+    def _rank(self, site: str, t: int, pos: np.ndarray) -> np.ndarray:
+        if self.device is not None and len(pos) >= device_rank.DEVICE_MIN_BATCH:
+            self.rank_calls[site, "device"] += 1
+            return self.device.rank1(t, pos)
+        self.rank_calls[site, "host"] += 1
+        return self.levels[t].rank1(pos)
 
     def _build(self, rows: np.ndarray, cols: np.ndarray):
         k, k2, h = self.k, self.k * self.k, self.h
@@ -94,8 +116,7 @@ class K2Tree:
     # The row/col expansion is *batched*: many fixed coordinates traverse the
     # tree together, level-synchronously, carrying a query-id column; each
     # level issues ONE vectorized rank1 over the concatenated child bit
-    # positions (the k²-tree hot op — routable to the Pallas kernel via
-    # `repro.core.succinct.bitvector.set_rank_backend`).
+    # positions (the k²-tree hot op — on the device on a TPU backend).
 
     def access(self, r: int, c: int) -> int:
         k, k2 = self.k, self.k * self.k
@@ -106,7 +127,7 @@ class K2Tree:
             bitpos = block * k2 + child
             if bitpos >= self.levels[t].n or not int(self.levels[t].access(bitpos)):
                 return 0
-            block = int(self.levels[t].rank1(bitpos))
+            block = int(self._rank("access", t, np.array([bitpos]))[0])
         return 1
 
     def row(self, r: int) -> np.ndarray:
@@ -163,7 +184,7 @@ class K2Tree:
             bitpos = bitpos[setbit]
             qids, fvals, prefixes = new_qids[setbit], new_fvals[setbit], new_prefix[setbit]
             if t < self.h - 1:
-                blocks = lv.rank1(bitpos)  # one batched rank per level
+                blocks = self._rank("descent", t, bitpos)  # one batched rank per level
             else:
                 keep = prefixes < limit_free
                 qids, coords = qids[keep], prefixes[keep]

@@ -1,8 +1,10 @@
-"""Pallas TPU kernels for the framework's compute hot spots.
+"""Device kernels for the framework's compute hot spots.
 
-Each kernel lives in its own module (pl.pallas_call + BlockSpec), has a
-pure-jnp oracle in `ref.py`, and a jitted wrapper in `ops.py` that picks
-interpret mode off-TPU. See tests/test_kernels_*.py for the sweep tests.
+Each Pallas kernel lives in its own module (pl.pallas_call + BlockSpec),
+has a pure-jnp oracle in `ref.py`, and a jitted wrapper in `ops.py` that
+picks interpret mode off-TPU. `bitvec_rank`, the query path's one device
+op, is plain XLA instead (see its module). See tests/test_kernels.py for
+the sweep tests.
 """
 from repro.kernels import ops, ref
 from repro.kernels.ops import (

@@ -1,61 +1,32 @@
-"""Batched bitvector rank1 Pallas kernel (query-time hot op of the k²-tree).
+"""Batched bitvector rank1 on the device (the k²-tree descent's one device op).
 
-rank1(pos) = word_ranks[pos/32] + popcount(words[pos/32] & mask(pos%32)).
-Popcount is the SWAR bit dance on uint32 lanes — no LUT, pure VPU ops.
-Full words + prefix ranks are resident; positions are blocked on the grid.
+rank1(pos) = word_ranks[off + pos/32] + popcount(words[off + pos/32] & mask(pos%32))
+
+Plain XLA: two gathers and ``lax.population_count``, fused by the compiler.
+There is no Pallas kernel on this path: a kernel body cannot index a VMEM
+ref with a vector of word ids on TPU, and holding the whole word array in
+one block does not fit a kernel's scoped memory at real level sizes. XLA
+gathers straight from HBM at any width (``tests/test_tpu_compile.py``
+compiles it for a v5e at 2²⁴ words).
+
+`offset` is a traced scalar, so one compiled program serves every level of
+a tree whose levels share one concatenated word array
+(:class:`repro.core.succinct.device_rank.DeviceLevels`).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+from jax import lax
 
 
-def _popcount32(x):
-    m1 = jnp.uint32(0x55555555)
-    m2 = jnp.uint32(0x33333333)
-    m4 = jnp.uint32(0x0F0F0F0F)
-    x = x - ((x >> 1) & m1)
-    x = (x & m2) + ((x >> 2) & m2)
-    x = (x + (x >> 4)) & m4
-    return ((x * jnp.uint32(0x01010101)) >> 24).astype(jnp.int32)
-
-
-def _rank_kernel(pos_ref, words_ref, ranks_ref, o_ref):
-    pos = pos_ref[...]
-    w = pos >> 5
-    rem = (pos & 31).astype(jnp.uint32)
-    word = words_ref[w]
-    mask = jnp.where(rem == 0, jnp.uint32(0), (jnp.uint32(1) << rem) - jnp.uint32(1))
-    o_ref[...] = ranks_ref[w] + _popcount32(word & mask)
-
-
-def bitvec_rank(words, word_ranks, positions, *, block_q=1024, interpret=False):
-    """words: (W,) uint32; word_ranks: (W,) int32 exclusive prefix;
-    positions: (Q,) int32 with pos/32 < W. Returns rank1 at each position.
-
-    Q may be any size: positions are padded up to the block boundary (pad
-    queries re-read position 0, always in-bounds) and the pad is sliced off.
-    """
-    (W,) = words.shape
-    (Q,) = positions.shape
-    if Q == 0:
-        return jnp.zeros(0, jnp.int32)
-    block_q = min(block_q, Q)
-    pad = (-Q) % block_q
-    if pad:
-        positions = jnp.pad(positions, (0, pad))
-    qp = Q + pad
-    out = pl.pallas_call(
-        _rank_kernel,
-        grid=(qp // block_q,),
-        in_specs=[
-            pl.BlockSpec((block_q,), lambda i: (i,)),
-            pl.BlockSpec((W,), lambda i: (0,)),
-            pl.BlockSpec((W,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block_q,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((qp,), jnp.int32),
-        interpret=interpret,
-    )(positions, words, word_ranks)
-    return out[:Q] if pad else out
+@jax.jit
+def bitvec_rank(words, word_ranks, positions, offset=0):
+    """words: (W,) uint32; word_ranks: (W,) int32 exclusive prefix popcounts;
+    positions: (Q,) int32 bit positions with ``offset + pos // 32 < W``.
+    Returns (Q,) int32 rank1 at each position. Callers validate positions:
+    an out-of-range gather index is clamped, not reported."""
+    w = offset + (positions >> 5)
+    rem = (positions & 31).astype(jnp.uint32)
+    mask = (jnp.uint32(1) << rem) - jnp.uint32(1)  # rem == 0 -> 0
+    return word_ranks[w] + lax.population_count(words[w] & mask).astype(jnp.int32)

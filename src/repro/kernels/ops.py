@@ -4,7 +4,8 @@ On non-TPU backends the kernels run in `interpret=True` mode (the kernel
 body executes as traced JAX ops — bit-exact correctness, no Mosaic); on TPU
 they compile to Mosaic. Models call these wrappers through the
 `use_pallas` config switch so CPU dry-runs lower the pure-jnp reference
-path while TPU runs get the kernels.
+path while TPU runs get the kernels. `bitvec_rank` is the exception: it
+is plain XLA on every backend (see `repro.kernels.bitvec_rank`).
 """
 from __future__ import annotations
 
@@ -62,9 +63,7 @@ def digram_pair_counts(its, cnts, *, block_n=256):
     return _digram_pair_counts(its, cnts, block_n=block_n, interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("block_q",))
-def bitvec_rank(words, word_ranks, positions, *, block_q=1024):
-    return _bitvec_rank(words, word_ranks, positions, block_q=block_q, interpret=_interpret())
+bitvec_rank = _bitvec_rank  # plain XLA, already jitted: no interpret mode
 
 
 __all__ = [

@@ -208,62 +208,150 @@ def test_k2_batched_matches_scalar(seed):
         assert np.array_equal(cols[idx == qi], t.row(int(qs[qi])))
 
 
-def test_pallas_rank_backend_parity():
-    """The Pallas bitvec_rank route must agree with the numpy rank path
-    (numpy is the parity oracle), including i == n and odd batch sizes."""
-    from repro.core.succinct import set_rank_backend
+# ---------------- device rank (XLA on CPU here; the same program on a TPU) ----------------
+def _levels(rng, sizes):
+    return [BitVector(rng.integers(0, 2, n).astype(np.uint8)) for n in sizes]
+
+
+def test_device_rank_parity():
+    """DeviceLevels.rank1 == BitVector.rank1 on every level of a
+    concatenated layout, including i == n and odd batch sizes."""
+    from repro.core.succinct.device_rank import DeviceLevels
 
     rng = np.random.default_rng(11)
-    bits = rng.integers(0, 2, 4097).astype(np.uint8)
-    bv = BitVector(bits)
-    # odd-sized batch (not a multiple of the kernel block) + boundary values
-    pos = np.concatenate([rng.integers(0, bv.n + 1, 997), [0, bv.n]]).astype(np.int64)
-    want = bv._rank1_numpy(pos)
-    old = set_rank_backend("pallas")
-    try:
-        got = bv.rank1(pos)
-    finally:
-        set_rank_backend(old)
-    assert np.array_equal(got, want)
+    levels = _levels(rng, [4097, 64, 1, 3000])
+    dev = DeviceLevels(levels)
+    for t, bv in enumerate(levels):
+        for q in (1, 33, 997):
+            pos = np.concatenate([rng.integers(0, bv.n + 1, q), [0, bv.n]])
+            assert np.array_equal(dev.rank1(t, pos), bv.rank1(pos)), (t, q)
 
 
-def test_rank_backend_env_unknown_warns_then_falls_back():
-    """ITR_RANK_BACKEND with an unknown value must warn once at import and
-    fall back to numpy — never crash, never silently pick pallas. The knob
-    is read at module import, so probe it in a fresh interpreter."""
-    import os
-    import subprocess
-    import sys
-    from pathlib import Path
+def test_device_rank_bucket_padding():
+    """Positions pad to power-of-two buckets (>= MIN_POSITIONS) and the
+    word array to a power-of-two width; answers at bucket edges and for an
+    empty batch are unaffected by the pad."""
+    from repro.core.succinct import device_rank as dr
 
-    code = (
-        "import warnings\n"
-        "with warnings.catch_warnings(record=True) as w:\n"
-        "    warnings.simplefilter('always')\n"
-        "    from repro.core.succinct import bitvector\n"
-        "assert bitvector.get_rank_backend() == 'numpy', bitvector.get_rank_backend()\n"
-        "msgs = [str(x.message) for x in w]\n"
-        "assert any('ITR_RANK_BACKEND' in m and 'bogus' in m for m in msgs), msgs\n"
-        "print('OK')\n"
-    )
-    src = str(Path(__file__).resolve().parent.parent / "src")
-    env = {**os.environ, "ITR_RANK_BACKEND": "bogus", "PYTHONPATH": src}
-    proc = subprocess.run([sys.executable, "-c", code], env=env,
-                          capture_output=True, text=True, timeout=120)
-    assert proc.returncode == 0, proc.stderr
-    assert "OK" in proc.stdout
+    assert [dr.position_bucket(q) for q in (0, 1, 256, 257, 4096, 4097)] == \
+        [256, 256, 256, 512, 4096, 8192]
+    assert [dr.width_bucket(w) for w in (1, 1024, 1025)] == [1024, 1024, 2048]
+    rng = np.random.default_rng(5)
+    levels = _levels(rng, [40_000, 777])
+    dev = dr.DeviceLevels(levels)
+    assert len(dev.words) == dr.width_bucket(sum(len(lv.words) + 1 for lv in levels))
+    for q in (0, 255, 256, 257, 511, 513):
+        pos = rng.integers(0, levels[0].n + 1, q)
+        assert np.array_equal(dev.rank1(0, pos), levels[0].rank1(pos)), q
+    assert dev.rank1(1, np.zeros(0, np.int64)).shape == (0,)
 
 
-def test_set_rank_backend_rejects_unknown():
-    from repro.core.succinct import set_rank_backend
+def test_device_rank_rejects_out_of_range_and_int32_overflow():
+    """Indices the device would clamp or wrap raise on the host instead."""
+    from types import SimpleNamespace
 
-    with pytest.raises(ValueError):
-        set_rank_backend("bogus")
+    from repro.core.succinct.device_rank import DeviceLevels
+
+    bv = BitVector(np.ones(100, dtype=np.uint8))
+    dev = DeviceLevels([bv])
+    with pytest.raises(IndexError):
+        dev.rank1(0, np.array([101]))
+    with pytest.raises(IndexError):
+        dev.rank1(0, np.array([-1, 3]))
+    huge = SimpleNamespace(n=2**31, n_ones=0, words=np.zeros(1, np.uint32),
+                           word_ranks=np.zeros(2, np.int64))
+    with pytest.raises(OverflowError):
+        DeviceLevels([bv, huge])
+
+
+def _on_device(monkeypatch):
+    """Take the TPU branch of the platform choice on this backend."""
+    from repro.core.succinct import device_rank
+
+    monkeypatch.setattr(device_rank, "enabled", lambda: True)
+
+
+def test_k2_rows_many_on_device_matches_host(monkeypatch):
+    """A multi-level tree placed on the device answers rows_many/cols_many
+    exactly as the host tree, with the descent's wide rank batches counted
+    on the device; a tree loaded through from_levels is placed too."""
+    rng = np.random.default_rng(9)
+    n, m = 300, 500
+    r, c = _random_matrix(rng, n, m, 0.02)
+    host = K2Tree(r, c, n, m)
+    assert host.device is None
+    _on_device(monkeypatch)
+    dev = K2Tree(r, c, n, m)
+    assert dev.device is not None and dev.h >= 8
+    qs = np.concatenate([rng.integers(0, n, 200), [-1, n + 3]]).astype(np.int64)
+    for a, b in zip(dev.rows_many(qs), host.rows_many(qs)):
+        assert np.array_equal(a, b)
+    for a, b in zip(dev.cols_many(qs), host.cols_many(qs)):
+        assert np.array_equal(a, b)
+    assert dev.rank_calls["descent", "device"] > 0
+    assert host.rank_calls["descent", "device"] == 0
+    loaded = K2Tree.from_levels(n, m, dev.k, dev.h, dev.n_points,
+                                [lv.words for lv in dev.levels],
+                                [lv.n for lv in dev.levels])
+    assert loaded.device is not None
+    for a, b in zip(loaded.rows_many(qs), host.rows_many(qs)):
+        assert np.array_equal(a, b)
+    assert loaded.rank_calls["descent", "device"] > 0
+
+
+def test_small_rank_batches_stay_on_host_and_are_counted(monkeypatch):
+    """Below DEVICE_MIN_BATCH a rank runs on the host even with the levels
+    on the device, and the split is visible per call site."""
+    from repro.core.succinct.device_rank import DEVICE_MIN_BATCH
+
+    _on_device(monkeypatch)
+    rng = np.random.default_rng(2)
+    r, c = _random_matrix(rng, 64, 64, 0.05)
+    t = K2Tree(r, c, 64, 64)
+    t.row(int(r[0]))
+    assert t.rank_calls["descent", "device"] == 0
+    assert t.rank_calls["descent", "host"] > 0
+    t.access(int(r[0]), int(c[0]))
+    assert t.rank_calls["access", "host"] > 0
+    big = np.arange(64, dtype=np.int64).repeat(DEVICE_MIN_BATCH)
+    t.rows_many(big)
+    assert t.rank_calls["descent", "device"] > 0
+
+
+def test_device_rank_failure_raises(monkeypatch):
+    """A device error surfaces to the caller: no numpy answer stands in."""
+    from repro.core.succinct import device_rank
+
+    _on_device(monkeypatch)
+    rng = np.random.default_rng(4)
+    r, c = _random_matrix(rng, 128, 128, 0.05)
+    t = K2Tree(r, c, 128, 128)
+
+    def broken(*args):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(device_rank.DeviceLevels, "rank1", broken)
+    with pytest.raises(RuntimeError, match="device lost"):
+        t.rows_many(np.arange(128, dtype=np.int64))
+    assert t.rank_calls["descent", "host"] == 0
+
+
+def test_rank_placement_follows_platform(monkeypatch):
+    """The platform alone decides: a CPU backend keeps the levels on the
+    host, a TPU backend places them; no environment knob is consulted."""
+    import jax
+
+    from repro.core.succinct import device_rank
+
+    assert jax.default_backend() == "cpu"
+    assert not device_rank.enabled()
+    assert K2Tree(np.array([1]), np.array([2]), 8, 8).device is None
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert device_rank.enabled()
 
 
 def test_kernel_bitvec_rank_arbitrary_batch_sizes():
-    """The kernel itself pads non-multiple-of-block position batches."""
-    jax = pytest.importorskip("jax")
+    """The XLA rank op answers any batch size, at any level offset."""
     import jax.numpy as jnp
 
     from repro.kernels.bitvec_rank import bitvec_rank
@@ -271,12 +359,17 @@ def test_kernel_bitvec_rank_arbitrary_batch_sizes():
     rng = np.random.default_rng(0)
     bits = rng.integers(0, 2, 2048).astype(np.uint8)
     bv = BitVector(bits)
-    words = jnp.asarray(np.concatenate([bv.words, np.zeros(1, np.uint32)]))
-    ranks = jnp.asarray(bv.word_ranks.astype(np.int32))
+    words = np.concatenate([bv.words, np.zeros(1, np.uint32)])
+    ranks = bv.word_ranks.astype(np.int32)
     for q in [1, 7, 64, 100, 1023]:
-        pos = rng.integers(0, bv.n, q).astype(np.int32)
-        out = bitvec_rank(words, ranks, jnp.asarray(pos), block_q=64, interpret=True)
-        assert np.array_equal(np.asarray(out), bv._rank1_numpy(pos.astype(np.int64)))
+        pos = rng.integers(0, bv.n + 1, q).astype(np.int32)
+        out = bitvec_rank(jnp.asarray(words), jnp.asarray(ranks), jnp.asarray(pos))
+        assert np.array_equal(np.asarray(out), bv.rank1(pos.astype(np.int64)))
+        # the same level behind 5 leading words of another level
+        out = bitvec_rank(jnp.asarray(np.concatenate([np.full(5, 7, np.uint32), words])),
+                          jnp.asarray(np.concatenate([np.full(5, 9, np.int32), ranks])),
+                          jnp.asarray(pos), 5)
+        assert np.array_equal(np.asarray(out), bv.rank1(pos.astype(np.int64)))
 
 
 @settings(max_examples=25, deadline=None)
