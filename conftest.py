@@ -9,9 +9,15 @@ hypothesis install always wins — the shim is only used on ImportError.
 Tests marked ``@pytest.mark.slow`` (large-budget randomized suites) are
 skipped by the tier-1 run and selected by the nightly/manual CI lane via
 ``pytest -m slow`` (.github/workflows/nightly.yml).
+
+``@pytest.mark.time_limit(seconds)`` fails a test that runs longer than
+`seconds` (a ``SIGALRM`` timer, so only on the main thread of a POSIX
+process; elsewhere the mark is ignored).
 """
 import importlib.util
+import signal
 import sys
+import threading
 from pathlib import Path
 
 import pytest
@@ -31,6 +37,29 @@ def pytest_configure(config):
         "markers",
         "slow: large-budget randomized suite; tier-1 skips it, the nightly "
         "lane selects it with `pytest -m slow`")
+    config.addinivalue_line(
+        "markers", "time_limit(seconds): fail the test past `seconds`")
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    mark = item.get_closest_marker("time_limit")
+    if (mark is None or not hasattr(signal, "setitimer")
+            or threading.current_thread() is not threading.main_thread()):
+        yield
+        return
+    seconds = float(mark.args[0])
+
+    def expire(signum, frame):
+        raise TimeoutError(f"{item.nodeid} ran past its {seconds:g} s limit")
+
+    previous = signal.signal(signal.SIGALRM, expire)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
 
 
 def pytest_collection_modifyitems(config, items):

@@ -57,6 +57,7 @@ from repro.core.hypergraph import _ragged_take
 from repro.core.result_cache import QueryResultCache
 from repro.core.succinct import K2Tree
 from repro.persist.crash import crash_point
+from repro.tracing import span, traced
 
 _EMPTY = np.zeros(0, dtype=np.int64)
 
@@ -376,6 +377,7 @@ class TripleQueryEngine:
         return self.encoded.incidence.row(node)
 
     # -- batched seeding -------------------------------------------------
+    @traced("itr.engine.seed")
     def _seed_batch(self, s: np.ndarray, p: np.ndarray, o: np.ndarray):
         """Start-graph edge ids seeding each query; returns (qids, edge_ids)."""
         nq = len(s)
@@ -471,9 +473,11 @@ class TripleQueryEngine:
         else:
             res = self._run_batch_unique(s, p, o)
         if not self.delta.is_empty:
-            res = self.delta.merge_batch(res, s, p, o)
+            with span("itr.engine.overlay"):
+                res = self.delta.merge_batch(res, s, p, o)
         return res
 
+    @traced("itr.engine.scalar")
     def _run_scalar_batch(self, s: np.ndarray, p: np.ndarray, o: np.ndarray):
         """Per-query worklist over a tiny batch, frontier-shaped results."""
         qids: list[int] = []
@@ -497,6 +501,12 @@ class TripleQueryEngine:
 
     def _run_batch_unique(self, s: np.ndarray, p: np.ndarray, o: np.ndarray):
         qids, eids = self._seed_batch(s, p, o)
+        return self._frontier(s, p, o, qids, eids)
+
+    @traced("itr.engine.frontier")
+    def _frontier(self, s, p, o, qids: np.ndarray, eids: np.ndarray):
+        """The level loop: expand the seeded edges rule by rule, keeping
+        the terminals each pattern matches."""
         labels = self._sorted_labels[eids]
         ranks = self._sorted_ranks[eids]
         take = _ragged_take(self._sorted_offsets, eids, ranks)
@@ -597,13 +607,14 @@ class TripleQueryEngine:
         nu = len(uniq)
         entries: list = [None] * nu
         miss: list[int] = []
-        for i in range(nu):
-            hit = cache.lookup(uniq[i, 0], uniq[i, 1], uniq[i, 2]) \
-                if cache is not None else None
-            if hit is None:
-                miss.append(i)
-            else:
-                entries[i] = hit
+        with span("itr.engine.cache"):
+            for i in range(nu):
+                hit = cache.lookup(uniq[i, 0], uniq[i, 1], uniq[i, 2]) \
+                    if cache is not None else None
+                if hit is None:
+                    miss.append(i)
+                else:
+                    entries[i] = hit
         if miss:
             mi = np.asarray(miss, dtype=np.int64)
             fresh = self._execute_unique(uniq[mi, 0], uniq[mi, 1], uniq[mi, 2])

@@ -19,6 +19,8 @@ import functools
 
 import numpy as np
 
+from repro.tracing import traced
+
 #: rank batches smaller than this stay on the host, where one device
 #: dispatch and two transfers are expected to cost more than numpy (the
 #: threshold is not measured on a chip); every call is counted per site
@@ -62,6 +64,7 @@ def rank_args(width: int, bucket: int, sharding=None) -> tuple:
 
 
 @functools.lru_cache(maxsize=None)
+@traced("itr.k2.compile")
 def _program(width: int, bucket: int):
     """The compiled rank for one (width bucket, position bucket) pair."""
     from repro.kernels.bitvec_rank import bitvec_rank

@@ -14,7 +14,7 @@ column expansion a simple per-level frontier sweep.
 On a TPU backend the levels are also laid out on the device once, when the
 tree is built or loaded, and the descent's batched rank runs there
 (`repro.core.succinct.device_rank`); `rank_calls` counts rank calls per
-call site and side.
+call site and side, `rank_positions` the positions asked and launched.
 """
 from __future__ import annotations
 
@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.core.succinct import device_rank
 from repro.core.succinct.bitvector import BitVector
+from repro.tracing import span, traced
 
 
 class K2Tree:
@@ -71,13 +72,24 @@ class K2Tree:
             if device_rank.enabled() else None
         #: rank calls by (call site, "device" | "host")
         self.rank_calls: Counter = Counter()
+        #: positions by ("device" | "host", "asked" | "launched"): launched
+        #: counts the device's bucket padding, so asked / launched is the
+        #: share of the rank's work that was useful
+        self.rank_positions: Counter = Counter()
 
     def _rank(self, site: str, t: int, pos: np.ndarray) -> np.ndarray:
-        if self.device is not None and len(pos) >= device_rank.DEVICE_MIN_BATCH:
+        q = len(pos)
+        if self.device is not None and q >= device_rank.DEVICE_MIN_BATCH:
             self.rank_calls[site, "device"] += 1
-            return self.device.rank1(t, pos)
+            self.rank_positions["device", "asked"] += q
+            self.rank_positions["device", "launched"] += device_rank.position_bucket(q)
+            with span("itr.k2.rank", side="device"):
+                return self.device.rank1(t, pos)
         self.rank_calls[site, "host"] += 1
-        return self.levels[t].rank1(pos)
+        self.rank_positions["host", "asked"] += q
+        self.rank_positions["host", "launched"] += q
+        with span("itr.k2.rank", side="host"):
+            return self.levels[t].rank1(pos)
 
     def _build(self, rows: np.ndarray, cols: np.ndarray):
         k, k2, h = self.k, self.k * self.k, self.h
@@ -150,6 +162,7 @@ class K2Tree:
         """Batched column expansion; see :meth:`rows_many`."""
         return self._lines(cs, axis=1)
 
+    @traced("itr.k2.descent")
     def _lines(self, fixed: np.ndarray, axis: int) -> tuple[np.ndarray, np.ndarray]:
         k, k2 = self.k, self.k * self.k
         fixed = np.asarray(fixed, dtype=np.int64)

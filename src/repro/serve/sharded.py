@@ -67,6 +67,7 @@ order, so threaded and sequential flushes are byte-identical.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -103,6 +104,7 @@ from repro.distributed.rebalance import (
 from repro.persist.crash import crash_point
 from repro.serve.concurrency import RWLock, resolve_serve_threads
 from repro.serve.triple_service import MicroBatchService
+from repro.tracing import span
 
 # sentinel: "create a default shared QueryResultCache unless disabled by env"
 _DEFAULT_CACHE = object()
@@ -155,11 +157,6 @@ class ShardedServiceStats:
     string_queries: int = 0   # query_strings / query_bgp_strings calls
     unknown_term_empties: int = 0  # string queries short-circuited to empty
     total_s: float = 0.0
-    last_flush_qps: float = 0.0
-
-    @property
-    def qps(self) -> float:
-        return self.queries / self.total_s if self.total_s > 0 else 0.0
 
 
 class ShardedTripleService(MicroBatchService):
@@ -192,6 +189,8 @@ class ShardedTripleService(MicroBatchService):
         # so two threads of ONE flush must not enter the same engine at once
         self._engine_locks = [threading.Lock() for _ in engines]
         self._stats_lock = threading.Lock()  # stats blocks are not atomic
+        # flush ids: the `flush` stat of a flush's spans on pool threads
+        self._flush_ids = itertools.count(1)
         #: scatter fan-out width (threads per flush); 1 = sequential
         self.serve_threads = resolve_serve_threads(serve_threads)
         self._pool: ThreadPoolExecutor | None = None  # lazy, sized on first use
@@ -277,26 +276,32 @@ class ShardedTripleService(MicroBatchService):
         per-engine scratch, stats) is locked at its own level.
         """
         n = len(s)
+        flush = next(self._flush_ids)
         t0 = time.perf_counter()
-        with self._rw.read():
-            group = None
-            if self._replicas is not None and not self._rw.write_held:
-                # write_held while we hold read means WE are the writer (a
-                # write-locked probe, e.g. contains_triples mid-mutation):
-                # it must observe the primary's half-applied state, not a
-                # replica's. Plain readers can never see write_held here.
-                group = self._replicas.acquire()
-            if group is not None:
-                try:
-                    # the whole flush runs on ONE consistent replica group,
-                    # so merged scatter results never mix freshness levels;
-                    # the group's own read lock excludes its WAL-tail applies
-                    with group.service._rw.read():
-                        view = group.service._run(s, p, o)
-                finally:
-                    self._replicas.release(group)
-            else:
-                view = self._run(s, p, o)
+        with span("itr.flush", flush=flush):
+            with span("itr.wait.rwlock"):
+                self._rw.acquire_read()
+            try:
+                group = None
+                if self._replicas is not None and not self._rw.write_held:
+                    # write_held while we hold read means WE are the writer (a
+                    # write-locked probe, e.g. contains_triples mid-mutation):
+                    # it must observe the primary's half-applied state, not a
+                    # replica's. Plain readers can never see write_held here.
+                    group = self._replicas.acquire()
+                if group is not None:
+                    try:
+                        # the whole flush runs on ONE consistent replica group,
+                        # so merged scatter results never mix freshness levels;
+                        # the group's own read lock excludes its WAL-tail applies
+                        with group.service._rw.read():
+                            view = group.service._run(s, p, o, flush)
+                    finally:
+                        self._replicas.release(group)
+                else:
+                    view = self._run(s, p, o, flush)
+            finally:
+                self._rw.release_read()
         dt = time.perf_counter() - t0
         with self._stats_lock:
             st = self.stats
@@ -304,7 +309,6 @@ class ShardedTripleService(MicroBatchService):
             st.flushes += 1
             st.results += view.total_results()
             st.total_s += dt
-            st.last_flush_qps = n / dt if dt > 0 else 0.0
             if group is not None:
                 st.replica_flushes += 1
         return view
@@ -473,43 +477,47 @@ class ShardedTripleService(MicroBatchService):
             return self._pool
 
     # -- scatter-gather core ---------------------------------------------
-    def _run(self, s: np.ndarray, p: np.ndarray, o: np.ndarray) -> QueryResultView:
-        # service-level dedup: route and merge each unique pattern once
-        key = np.stack([s, p, o], axis=1)
-        uniq, inv = np.unique(key, axis=0, return_inverse=True)
-        inv = inv.reshape(-1)
-        nu = len(uniq)
-        u_s, u_p, u_o = uniq[:, 0], uniq[:, 1], uniq[:, 2]
-        routes = self._route_patterns(u_s, u_p, u_o)
-        cache = self.cache
+    def _run(self, s: np.ndarray, p: np.ndarray, o: np.ndarray,
+             flush: int) -> QueryResultView:
+        """One flush's unique patterns, routed, executed on the shards and
+        merged; `flush` is the id its spans on pool threads carry."""
+        with span("itr.route"):
+            # service-level dedup: route and merge each unique pattern once
+            key = np.stack([s, p, o], axis=1)
+            uniq, inv = np.unique(key, axis=0, return_inverse=True)
+            inv = inv.reshape(-1)
+            nu = len(uniq)
+            u_s, u_p, u_o = uniq[:, 0], uniq[:, 1], uniq[:, 2]
+            routes = self._route_patterns(u_s, u_p, u_o)
+            cache = self.cache
 
-        entries: list = [None] * nu
-        # scattered patterns: the merged cross-shard result is itself cached
-        # (reserved namespace), so a warm repeat is one lookup, not a fan-out
-        scatter: list[int] = []
-        merged_hits = 0
-        for u in np.flatnonzero(routes < 0):
-            u = int(u)
-            hit = cache.lookup(u_s[u], u_p[u], u_o[u], shard=self._merged_ns) \
-                if cache is not None else None
-            if hit is None:
-                scatter.append(u)
-            else:
-                entries[u] = hit
-                merged_hits += 1
-        scatter = np.asarray(scatter, dtype=np.int64)
-        degraded = 0
-        if self.failed_shards:
-            # every pattern owned by (or scattered across) a failed shard is
-            # answered with that shard's rows missing — count the holes
-            failed = sorted(self.failed_shards)
-            degraded = int(np.isin(routes, failed).sum()) + len(scatter)
-        with self._stats_lock:
-            self.stats.unique_patterns += nu
-            self.stats.merged_hits += merged_hits
-            self.stats.owned += int((routes >= 0).sum())
-            self.stats.scattered += int((routes < 0).sum())
-            self.stats.degraded_patterns += degraded
+            entries: list = [None] * nu
+            # scattered patterns: the merged cross-shard result is itself cached
+            # (reserved namespace), so a warm repeat is one lookup, not a fan-out
+            scatter: list[int] = []
+            merged_hits = 0
+            for u in np.flatnonzero(routes < 0):
+                u = int(u)
+                hit = cache.lookup(u_s[u], u_p[u], u_o[u], shard=self._merged_ns) \
+                    if cache is not None else None
+                if hit is None:
+                    scatter.append(u)
+                else:
+                    entries[u] = hit
+                    merged_hits += 1
+            scatter = np.asarray(scatter, dtype=np.int64)
+            degraded = 0
+            if self.failed_shards:
+                # every pattern owned by (or scattered across) a failed shard is
+                # answered with that shard's rows missing — count the holes
+                failed = sorted(self.failed_shards)
+                degraded = int(np.isin(routes, failed).sum()) + len(scatter)
+            with self._stats_lock:
+                self.stats.unique_patterns += nu
+                self.stats.merged_hits += merged_hits
+                self.stats.owned += int((routes >= 0).sum())
+                self.stats.scattered += int((routes < 0).sum())
+                self.stats.degraded_patterns += degraded
 
         # merge-missing scattered patterns accumulate one chunk per shard;
         # work items are collected first so they can fan out across the pool
@@ -529,37 +537,39 @@ class ShardedTripleService(MicroBatchService):
             # submitting reader would deadlock by writer preference)
             pool = self._ensure_pool()
             futs = [pool.submit(self._shard_entries, k,
-                                u_s[idx], u_p[idx], u_o[idx])
+                                u_s[idx], u_p[idx], u_o[idx], flush)
                     for k, _, idx in work]
-            results = [f.result() for f in futs]
+            with span("itr.wait.shards"):
+                results = [f.result() for f in futs]
         else:
-            results = [self._shard_entries(k, u_s[idx], u_p[idx], u_o[idx])
+            results = [self._shard_entries(k, u_s[idx], u_p[idx], u_o[idx], flush)
                        for k, _, idx in work]
         # merge in shard order (work is k-ascending): threaded and
         # sequential flushes produce byte-identical views
-        n_batches = 0
-        for (k, own, idx), (pos_entries, nb) in zip(work, results):
-            n_batches += nb
-            for j, u in enumerate(own):
-                entries[int(u)] = pos_entries[j]
-            for j, u in enumerate(scatter):
-                parts[int(u)].append(pos_entries[len(own) + j])
-        with self._stats_lock:
-            self.stats.shard_batches += n_batches
-        for u, chunks in parts.items():
-            # merged chunks are shared across duplicate tickets: read-only.
-            # A scattered result is deliberately held twice in the shared
-            # tier (per-shard chunks + this merged copy): the merged entry
-            # makes warm repeats O(1), while the per-shard chunks mean a
-            # single-shard invalidate() re-executes ONE shard, not all P.
-            entry = _freeze_entry(concat_ragged(chunks))
-            entries[u] = entry
-            if cache is not None:
-                cache.insert(u_s[u], u_p[u], u_o[u], entry,
-                             shard=self._merged_ns)
-        for u in range(nu):  # shards==0 or routing gaps: empty result
-            if entries[u] is None:
-                entries[u] = _freeze_entry(concat_ragged([]))
+        with span("itr.merge"):
+            n_batches = 0
+            for (k, own, idx), (pos_entries, nb) in zip(work, results):
+                n_batches += nb
+                for j, u in enumerate(own):
+                    entries[int(u)] = pos_entries[j]
+                for j, u in enumerate(scatter):
+                    parts[int(u)].append(pos_entries[len(own) + j])
+            with self._stats_lock:
+                self.stats.shard_batches += n_batches
+            for u, chunks in parts.items():
+                # merged chunks are shared across duplicate tickets: read-only.
+                # A scattered result is deliberately held twice in the shared
+                # tier (per-shard chunks + this merged copy): the merged entry
+                # makes warm repeats O(1), while the per-shard chunks mean a
+                # single-shard invalidate() re-executes ONE shard, not all P.
+                entry = _freeze_entry(concat_ragged(chunks))
+                entries[u] = entry
+                if cache is not None:
+                    cache.insert(u_s[u], u_p[u], u_o[u], entry,
+                                 shard=self._merged_ns)
+            for u in range(nu):  # shards==0 or routing gaps: empty result
+                if entries[u] is None:
+                    entries[u] = _freeze_entry(concat_ragged([]))
         return QueryResultView(entries, inv)
 
     def _route_patterns(self, s: np.ndarray, p: np.ndarray, o: np.ndarray
@@ -580,7 +590,7 @@ class ShardedTripleService(MicroBatchService):
             routes = np.where(routes == incoming, routes, -1)
         return routes
 
-    def _shard_entries(self, k: int, s, p, o) -> tuple[list, int]:
+    def _shard_entries(self, k: int, s, p, o, flush: int) -> tuple[list, int]:
         """One shard's entries for its sub-batch, in submission order —
         one engine micro-batch per `max_batch` chunk. Returns
         ``(entries, n_batches)``; runs under the shard's engine lock, so
@@ -589,12 +599,18 @@ class ShardedTripleService(MicroBatchService):
         engine = self.engines[k]
         out: list = []
         n_batches = 0
-        with self._engine_locks[k]:
-            for lo in range(0, len(s), self.max_batch):
-                hi = min(lo + self.max_batch, len(s))
-                view = engine.query_batch_view(s[lo:hi], p[lo:hi], o[lo:hi])
-                out.extend(view.entry(i) for i in range(view.n_queries))
-                n_batches += 1
+        lock = self._engine_locks[k]
+        with span("itr.wait.engine", flush=flush, shard=k):
+            lock.acquire()
+        try:
+            with span("itr.shard.exec", flush=flush, shard=k):
+                for lo in range(0, len(s), self.max_batch):
+                    hi = min(lo + self.max_batch, len(s))
+                    view = engine.query_batch_view(s[lo:hi], p[lo:hi], o[lo:hi])
+                    out.extend(view.entry(i) for i in range(view.n_queries))
+                    n_batches += 1
+        finally:
+            lock.release()
         return out, n_batches
 
     # -- mutation ---------------------------------------------------------

@@ -15,11 +15,11 @@ The engine's cross-request result cache makes dedup streaming: a pattern
 seen in any earlier flush (or earlier in this one) is answered from the
 cache instead of re-executing the frontier. Flush-time stats therefore
 separate *submitted* queries from *executed* unique patterns and *cache
-hits* — `qps` alone would hide the difference between a fast engine and a
-warm cache.
+hits*.
 
-The service is numpy-only — it runs wherever the engine runs — and keeps
-rolling throughput stats so serving dashboards can track queries/second.
+The service is numpy-only — it runs wherever the engine runs. With
+tracing on (:mod:`repro.tracing`), each ``query``/``query_many`` is an
+``itr.request`` span and its tuple building an ``itr.tuples`` span.
 
 Thread-safety: the pending queue is internally locked, and every
 request-plane entry (``query``/``query_many``/``flush``) takes its
@@ -41,6 +41,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from repro.core.query import QueryResultView, TripleQueryEngine
+from repro.tracing import span
 
 
 @dataclass
@@ -64,11 +65,6 @@ class ServiceStats:
     deleted: int = 0    # triples actually removed
     rebuilds: int = 0   # grammar recompressions (auto + explicit)
     total_s: float = 0.0
-    last_batch_qps: float = 0.0
-
-    @property
-    def qps(self) -> float:
-        return self.queries / self.total_s if self.total_s > 0 else 0.0
 
     @property
     def cache_hit_rate(self) -> float:
@@ -171,24 +167,30 @@ class MicroBatchService:
         results (anything already pending is flushed alongside, its
         tickets still owned by whoever submitted them). The ticket take
         is atomic, so concurrent `query` callers get disjoint batches."""
-        with self._pending_lock:
-            ticket = self._submit_locked(s, p, o)
-            cols = self._take_pending_locked()
-        return self._flush_columns(*cols).tuple_lists()[ticket]
+        with span("itr.request"):
+            with self._pending_lock:
+                ticket = self._submit_locked(s, p, o)
+                cols = self._take_pending_locked()
+            view = self._flush_columns(*cols)
+            with span("itr.tuples"):
+                return view.tuple_lists()[ticket]
 
     def query_many(self, patterns) -> list[tuple]:
         """patterns: iterable of (s, p, o) with None = unbound. Returns
         one result tuple per pattern, in order — results for tickets
         other callers already had pending are flushed alongside but not
         returned here (they belong to those callers' flush)."""
-        with self._pending_lock:
-            base = len(self._pending.s)
-            for s, p, o in patterns:
-                self._submit_locked(s, p, o)
-            cols = self._take_pending_locked()
-        if cols is None:
-            return []
-        return self._flush_columns(*cols).tuple_lists()[base:]
+        with span("itr.request"):
+            with self._pending_lock:
+                base = len(self._pending.s)
+                for s, p, o in patterns:
+                    self._submit_locked(s, p, o)
+                cols = self._take_pending_locked()
+            if cols is None:
+                return []
+            view = self._flush_columns(*cols)
+            with span("itr.tuples"):
+                return view.tuple_lists()[base:]
 
 
 class TripleQueryService(MicroBatchService):
@@ -231,7 +233,6 @@ class TripleQueryService(MicroBatchService):
         self.stats.queries += n
         self.stats.results += view.total_results()
         self.stats.total_s += dt
-        self.stats.last_batch_qps = n / dt if dt > 0 else 0.0
         if before is not None:
             # engine cache counters moved once per *unique* pattern: the
             # hit delta is served-from-cache, the miss delta is executed

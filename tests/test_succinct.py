@@ -318,6 +318,38 @@ def test_small_rank_batches_stay_on_host_and_are_counted(monkeypatch):
     assert t.rank_calls["descent", "device"] > 0
 
 
+@pytest.mark.time_limit(120)
+def test_rank_positions_count_asked_and_launched(monkeypatch):
+    """`rank_positions` counts the positions each side ranked: on the
+    device the launched ones are the padded buckets of the batches asked,
+    on the host the two are equal; `rank_calls` is unchanged by it."""
+    from repro.core.succinct import device_rank
+
+    _on_device(monkeypatch)
+    rng = np.random.default_rng(5)
+    r, c = _random_matrix(rng, 300, 500, 0.02)
+    t = K2Tree(r, c, 300, 500)
+    batches = []
+    rank1 = t.device.rank1
+
+    def spy(level, pos):
+        batches.append(len(pos))
+        return rank1(level, pos)
+
+    monkeypatch.setattr(t.device, "rank1", spy)
+    t.rows_many(rng.integers(0, 300, 200))
+    t.cols_many(rng.integers(0, 500, 300))
+    t.row(int(r[0]))  # narrow levels: host ranks
+    n = t.rank_positions
+    assert batches and n["device", "asked"] == sum(batches)
+    assert n["device", "launched"] == sum(device_rank.position_bucket(q) for q in batches)
+    assert n["device", "asked"] <= n["device", "launched"]
+    assert n["host", "asked"] == n["host", "launched"] > 0
+    assert t.rank_calls["descent", "device"] == len(batches)
+    assert set(n) == {("device", "asked"), ("device", "launched"),
+                      ("host", "asked"), ("host", "launched")}
+
+
 def test_device_rank_failure_raises(monkeypatch):
     """A device error surfaces to the caller: no numpy answer stands in."""
     from repro.core.succinct import device_rank
