@@ -64,7 +64,10 @@ class EncodedGrammar:
             pos += 1 + rank
         fn_ids = delta_decode(*self.edge_fn_stream, self.n_edges).astype(np.int64) - 1
         # reconstruct edges: zeta from ONE batched incidence-column traversal
-        # (all edges at once), nodes = zeta[pi] as a flat ragged gather
+        # (all edges at once), nodes = zeta[pi] as a flat ragged gather. Past
+        # DESCENT_MAX_BATCH edges that traversal goes level by level (each
+        # level one wide rank), not fused: a whole-matrix frontier is bounded
+        # by the tree, not by a batch; the answer is the same either way
         eidx, zeta_flat = self.incidence.cols_many(np.arange(self.n_edges, dtype=np.int64))
         zeta_counts = np.bincount(eidx, minlength=self.n_edges).astype(np.int64)
         zeta_starts = np.cumsum(zeta_counts) - zeta_counts

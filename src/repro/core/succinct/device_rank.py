@@ -1,17 +1,32 @@
-"""The k²-tree's levels on the device, and the batched rank that reads them.
+"""The k²-tree's levels on the device, and the programs that read them.
 
 Where JAX's default backend is a TPU, every :class:`~repro.core.succinct.
 K2Tree` lays its levels out as one device word array and one device rank
-array when it is built or loaded — never lazily from concurrent readers —
-and the batched ``rank1`` of its descent runs there
-(:func:`repro.kernels.bitvec_rank.bitvec_rank`). Elsewhere the levels
-stay on the host and numpy answers (``BitVector.rank1``, which is
-also the parity oracle). Nothing falls back: a device error raises.
+array when it is built or loaded — never lazily from concurrent readers.
+Two programs read them, one algorithm scheduled by batch size:
+
+* a batch of ``FUSED_MIN_BATCH`` to ``DESCENT_MAX_BATCH`` fixed
+  coordinates descends the whole tree in one launch
+  (:func:`repro.kernels.k2_descent.k2_descent`, through
+  :meth:`DeviceLevels.descend`);
+* any other descent (one coordinate, or a whole-matrix expansion) runs
+  level by level on the host, and ranks each level's positions on the
+  device where there are at least ``DEVICE_MIN_BATCH`` of them
+  (:func:`repro.kernels.bitvec_rank.bitvec_rank`, through
+  :meth:`DeviceLevels.rank1`).
+
+Elsewhere the levels stay on the host and numpy answers (``BitVector.
+rank1`` and the per-level descent, which are also the parity oracles).
+Nothing falls back: a device error raises.
 
 Compiles are bounded. The concatenated word array is padded to a power of
-two (its width bucket) and each batch of positions to a power of two of at
-least ``MIN_POSITIONS`` (its position bucket); one compiled program serves
-each (width bucket, position bucket) pair, whatever tree or level asks.
+two (its width bucket). A rank batch is padded to a power of two of at
+least ``MIN_POSITIONS`` (its position bucket); one rank program serves each
+(width bucket, position bucket) pair, whatever tree or level asks. A fused
+descent pads its coordinates to ``DESCENT_MAX_BATCH``; its program is keyed
+by the width bucket, the tree's arity and height, the axis, and two
+capacities (frontier entries per level, pairs returned) that each tree keeps
+per axis and only grows.
 """
 from __future__ import annotations
 
@@ -26,6 +41,16 @@ from repro.tracing import traced
 #: threshold is not measured on a chip); every call is counted per site
 #: and side in ``K2Tree.rank_calls``
 DEVICE_MIN_BATCH = 32
+#: the fewest fixed coordinates a fused descent takes. On a v5e even a
+#: batch of a few coordinates walks ~14 levels of a jamendo shard tree
+#: wide enough to rank on the device, one round trip each, where one
+#: fused launch costs about as much as two; a single coordinate (``row``,
+#: ``col``) keeps the per-level walk
+FUSED_MIN_BATCH = 2
+#: the most fixed coordinates one fused descent takes (the services'
+#: ``max_batch``); a larger batch, such as a whole-matrix expansion,
+#: descends level by level, where each level is already one wide launch
+DESCENT_MAX_BATCH = 1024
 MIN_POSITIONS = 256
 MIN_WIDTH = 1024
 _INT32_MAX = 2**31 - 1
@@ -72,9 +97,42 @@ def _program(width: int, bucket: int):
     return bitvec_rank.lower(*rank_args(width, bucket)).compile()
 
 
+def descent_args(width: int, h: int, sharding=None) -> tuple:
+    """Argument shapes of the fused descent: words, ranks, level offsets,
+    level bit counts, fixed coordinates and their count."""
+    import jax
+    import jax.numpy as jnp
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return (arg((width,), jnp.uint32), arg((width,), jnp.int32),
+            arg((h,), jnp.int32), arg((h,), jnp.int32),
+            arg((DESCENT_MAX_BATCH,), jnp.int32), arg((), jnp.int32))
+
+
+@functools.lru_cache(maxsize=None)
+@traced("itr.k2.compile")
+def _descent_program(width: int, k: int, h: int, axis: int, cap: int, out_cap: int):
+    """The compiled fused descent for one width bucket and capacity pair."""
+    from repro.kernels.k2_descent import k2_descent
+
+    return k2_descent.lower(*descent_args(width, h), k=k, axis=axis,
+                            cap=cap, out_cap=out_cap).compile()
+
+
+def descent_positions(q: int, sizes: np.ndarray, cap: int) -> tuple[int, int]:
+    """Frontier entries a fused descent of `q` coordinates processed
+    (asked) and the chunk-padded entries its loops ran over (launched)."""
+    from repro.kernels.k2_descent import CHUNK
+
+    entries = np.minimum(np.concatenate([[q], sizes[:-1]]), cap)
+    return int(entries.sum()), int((-(-entries // CHUNK) * CHUNK).sum())
+
+
 def compiled_programs() -> int:
-    """Number of rank programs this process has compiled."""
-    return _program.cache_info().currsize
+    """Number of rank and descent programs this process has compiled."""
+    return _program.cache_info().currsize + _descent_program.cache_info().currsize
 
 
 class DeviceLevels:
@@ -83,13 +141,17 @@ class DeviceLevels:
     Level t occupies entries ``[offsets[t], offsets[t] + W_t + 1)``: its
     ``W_t`` packed words and one zero word, so ``rank1(n)`` (one past the
     last bit) reads in bounds, next to its ``W_t + 1`` exclusive prefix
-    ranks. Every index the device sees is int32; a level or a tree that
-    would not fit raises ``OverflowError`` here instead of wrapping.
+    ranks. Every index the device sees is int32, a descent's coordinates
+    too; a level, a tree or a `side` (the tree's ``k**h``) that would not
+    fit raises ``OverflowError`` here instead of wrapping.
     """
 
-    def __init__(self, levels):
+    def __init__(self, levels, side: int = 0):
         import jax
 
+        if side > _INT32_MAX + 1:
+            raise OverflowError(
+                f"k2-tree side {side} exceeds int32 device coordinates")
         sizes = [len(lv.words) + 1 for lv in levels]
         for t, lv in enumerate(levels):
             if lv.n > _INT32_MAX or lv.n_ones > _INT32_MAX:
@@ -110,6 +172,8 @@ class DeviceLevels:
         self.nbytes = words.nbytes + ranks.nbytes
         self.words = jax.device_put(words)
         self.ranks = jax.device_put(ranks)
+        self.level_offsets = jax.device_put(self.offsets.astype(np.int32))
+        self.level_bits = jax.device_put(np.asarray(self.n_bits, dtype=np.int32))
 
     def rank1(self, t: int, i: np.ndarray) -> np.ndarray:
         """Batched rank1 on level `t` at int64 positions `i` in [0, n]."""
@@ -124,3 +188,22 @@ class DeviceLevels:
         out = _program(len(self.words), bucket)(
             self.words, self.ranks, pos, np.int32(self.offsets[t]))
         return np.asarray(out)[:q].astype(np.int64)
+
+    def descend(self, fixed: np.ndarray, k: int, axis: int, cap: int, out_cap: int):
+        """One fused descent of in-range coordinates `fixed` (at most
+        ``DESCENT_MAX_BATCH``): a level keeps at most `cap` entries, the
+        last at most `out_cap` pairs. Returns each level's true survivor
+        count and the (index into `fixed`, free coordinate) pairs kept,
+        sorted; a count above its capacity means they were cut short."""
+        q = len(fixed)
+        if not q <= min(cap, DESCENT_MAX_BATCH):
+            raise ValueError(f"{q} coordinates for a descent of capacity {cap}")
+        pad = np.zeros(DESCENT_MAX_BATCH, dtype=np.int32)
+        pad[:q] = fixed
+        h = len(self.n_bits)
+        out = np.asarray(_descent_program(len(self.words), k, h, axis, cap, out_cap)(
+            self.words, self.ranks, self.level_offsets, self.level_bits,
+            pad, np.int32(q))).astype(np.int64)
+        sizes = out[:h]
+        n = min(int(sizes[-1]), out_cap)
+        return sizes, out[h:h + n], out[h + out_cap:h + out_cap + n]

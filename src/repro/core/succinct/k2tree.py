@@ -12,9 +12,12 @@ keeps construction fully vectorized (digit-radix sort per level) and row/
 column expansion a simple per-level frontier sweep.
 
 On a TPU backend the levels are also laid out on the device once, when the
-tree is built or loaded, and the descent's batched rank runs there
-(`repro.core.succinct.device_rank`); `rank_calls` counts rank calls per
-call site and side, `rank_positions` the positions asked and launched.
+tree is built or loaded (`repro.core.succinct.device_rank`). A batch of
+`FUSED_MIN_BATCH` to `DESCENT_MAX_BATCH` fixed coordinates then descends
+every level in one device program; any other descent goes level by level,
+with each wide level's rank on the device. `descent_calls` counts which
+schedule ran, `rank_calls` the device launches and host ranks per call site
+and side, `rank_positions` the positions asked and launched.
 """
 from __future__ import annotations
 
@@ -25,6 +28,18 @@ import numpy as np
 from repro.core.succinct import device_rank
 from repro.core.succinct.bitvector import BitVector
 from repro.tracing import span, traced
+
+#: a fused descent's first capacities: frontier entries a level keeps (a
+#: 256-row batch of the jamendo stand-in peaks at ~50k) and pairs returned
+FRONTIER_CAPACITY = 2**17
+PAIR_CAPACITY = 4096
+#: a capacity that overflowed grows to the power of two at or above this
+#: many times the count that overflowed it, so later batches fit
+HEADROOM = 1.25
+
+
+def _grown(need: float, bound: int) -> int:
+    return min(bound, device_rank._pow2_at_least(int(np.ceil(need * HEADROOM)), 1))
 
 
 class K2Tree:
@@ -68,13 +83,21 @@ class K2Tree:
 
     def _place(self):
         """Upload the levels once, where the platform runs rank on the device."""
-        self.device = device_rank.DeviceLevels(self.levels) \
+        self.device = device_rank.DeviceLevels(self.levels, self.side) \
             if device_rank.enabled() else None
+        #: descents by schedule: "fused" launches, "fused_retry" launches
+        #: rerun after a level outgrew its capacity, "levels" per-level walks
+        self.descent_calls: Counter = Counter()
+        #: the fused descent's (frontier, pairs) capacities per axis; they
+        #: grow when a batch overflows them, and never shrink
+        self.capacities: dict = {}
         #: rank calls by (call site, "device" | "host")
         self.rank_calls: Counter = Counter()
-        #: positions by ("device" | "host", "asked" | "launched"): launched
-        #: counts the device's bucket padding, so asked / launched is the
-        #: share of the rank's work that was useful
+        #: positions by ("device" | "host" | "fused", "asked" | "launched"):
+        #: launched counts the device's padding, so asked / launched is the
+        #: share of the work that was useful; for "fused" they are the live
+        #: frontier entries and the chunk-padded entries the program's loops
+        #: ran over, summed over levels
         self.rank_positions: Counter = Counter()
 
     def _rank(self, site: str, t: int, pos: np.ndarray) -> np.ndarray:
@@ -126,9 +149,12 @@ class K2Tree:
 
     # ---------------- queries ----------------
     # The row/col expansion is *batched*: many fixed coordinates traverse the
-    # tree together, level-synchronously, carrying a query-id column; each
+    # tree together, level-synchronously, carrying a query-id column. On the
+    # device a batch of FUSED_MIN_BATCH..DESCENT_MAX_BATCH coordinates walks
+    # every level in one program (`repro.kernels.k2_descent`); otherwise each
     # level issues ONE vectorized rank1 over the concatenated child bit
-    # positions (the k²-tree hot op — on the device on a TPU backend).
+    # positions (on the device where the level is wide). Entries stay sorted
+    # by (query, free coordinate) at every level on both schedules.
 
     def access(self, r: int, c: int) -> int:
         k, k2 = self.k, self.k * self.k
@@ -164,20 +190,58 @@ class K2Tree:
 
     @traced("itr.k2.descent")
     def _lines(self, fixed: np.ndarray, axis: int) -> tuple[np.ndarray, np.ndarray]:
-        k, k2 = self.k, self.k * self.k
         fixed = np.asarray(fixed, dtype=np.int64)
         limit_fixed = self.n_rows if axis == 0 else self.n_cols
         limit_free = self.n_cols if axis == 0 else self.n_rows
-        ok = (fixed >= 0) & (fixed < limit_fixed)
-        qids = np.flatnonzero(ok).astype(np.int64)
-        fvals = fixed[qids]
+        qids = np.flatnonzero((fixed >= 0) & (fixed < limit_fixed)).astype(np.int64)
+        if self.n_points == 0 or len(qids) == 0:
+            return np.zeros(0, dtype=np.int64), np.zeros(0, dtype=np.int64)
+        if self.device is not None and \
+                device_rank.FUSED_MIN_BATCH <= len(qids) <= device_rank.DESCENT_MAX_BATCH:
+            idx, coords = self._fused(fixed[qids], axis)
+        else:
+            idx, coords = self._levels(fixed[qids], axis)
+        keep = coords < limit_free
+        return qids[idx[keep]], coords[keep]
+
+    def _fused(self, fvals: np.ndarray, axis: int) -> tuple[np.ndarray, np.ndarray]:
+        """The whole descent in one device launch. A batch that overflows a
+        capacity grows it and runs again: no answer is cut short."""
+        retry = False
+        while True:
+            cap, out_cap = self.capacities.get(axis, (FRONTIER_CAPACITY, PAIR_CAPACITY))
+            with span("itr.k2.fused", queries=len(fvals), capacity=cap, retry=retry):
+                sizes, idx, coords = self.device.descend(fvals, self.k, axis, cap, out_cap)
+            self.descent_calls["fused_retry" if retry else "fused"] += 1
+            self.rank_calls["descent", "device"] += 1
+            asked, launched = device_rank.descent_positions(len(fvals), sizes, cap)
+            self.rank_positions["fused", "asked"] += asked
+            self.rank_positions["fused", "launched"] += launched
+            over = np.flatnonzero(sizes > cap)
+            if not len(over) and sizes[-1] <= out_cap:
+                return idx, coords
+            need = sizes.astype(np.float64)
+            if len(over):  # levels past the first cut saw only the entries kept
+                need[over[0] + 1:] *= sizes[over[0]] / cap
+            # no level holds more than DESCENT_MAX_BATCH queries times
+            # min(k^(t+1), its ones) entries, so growth stops there
+            bound = device_rank.DESCENT_MAX_BATCH * max(
+                min(self.k ** (t + 1), lv.n_ones) for t, lv in enumerate(self.levels))
+            cap = max(cap, _grown(need.max(), bound))
+            self.capacities[axis] = (cap, min(cap, max(out_cap, _grown(need[-1], bound))))
+            retry = True
+
+    def _levels(self, fvals: np.ndarray, axis: int) -> tuple[np.ndarray, np.ndarray]:
+        """The per-level descent of in-range coordinates `fvals`: (index
+        into `fvals`, free coordinate) pairs, sorted, before the free axis'
+        limit is applied."""
+        k, k2 = self.k, self.k * self.k
+        self.descent_calls["levels"] += 1
+        qids = np.arange(len(fvals), dtype=np.int64)
         blocks = np.zeros(len(qids), dtype=np.int64)
         prefixes = np.zeros(len(qids), dtype=np.int64)  # free-axis coordinate prefix
         free = np.arange(k, dtype=np.int64)
-        empty = (np.zeros(0, dtype=np.int64), np.zeros(0, dtype=np.int64))
         for t in range(self.h):
-            if len(blocks) == 0:
-                return empty
             scale = k ** (self.h - 1 - t)
             fixed_digit = fvals // scale % k
             # candidate children: fixed axis digit fixed, free axis digit 0..k-1
@@ -196,14 +260,11 @@ class K2Tree:
                 setbit[valid] = lv.access(bitpos[valid]).astype(bool)
             bitpos = bitpos[setbit]
             qids, fvals, prefixes = new_qids[setbit], new_fvals[setbit], new_prefix[setbit]
+            if len(bitpos) == 0:
+                break
             if t < self.h - 1:
                 blocks = self._rank("descent", t, bitpos)  # one batched rank per level
-            else:
-                keep = prefixes < limit_free
-                qids, coords = qids[keep], prefixes[keep]
-                order = np.lexsort((coords, qids))
-                return qids[order], coords[order]
-        return empty
+        return qids, prefixes
 
     def to_dense(self) -> np.ndarray:
         out = np.zeros((self.n_rows, self.n_cols), dtype=np.uint8)

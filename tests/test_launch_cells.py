@@ -5,7 +5,7 @@ import jax
 import pytest
 
 from repro.configs import all_cells, get_arch
-from repro.launch.mesh import make_host_mesh, resolve_in_shardings, set_global_mesh
+from repro.launch.mesh import make_host_mesh, resolve_in_shardings
 from repro.launch.steps import build_cell
 
 # one representative shape per family kind keeps this under a minute
@@ -25,9 +25,11 @@ FAST_CELLS = [
 
 @pytest.fixture(scope="module", autouse=True)
 def host_mesh():
+    # the mesh is this module's alone: leaving it set would hand a CPU mesh
+    # to whichever test runs next in the process (a TPU compile among them)
     mesh = make_host_mesh()
-    set_global_mesh(mesh)
-    yield mesh
+    with jax.set_mesh(mesh):
+        yield mesh
 
 
 @pytest.mark.parametrize("arch,shape", FAST_CELLS)

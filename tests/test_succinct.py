@@ -262,6 +262,8 @@ def test_device_rank_rejects_out_of_range_and_int32_overflow():
                            word_ranks=np.zeros(2, np.int64))
     with pytest.raises(OverflowError):
         DeviceLevels([bv, huge])
+    with pytest.raises(OverflowError):  # coordinates of a descent are int32
+        DeviceLevels([bv], side=2**31 + 2)
 
 
 def _on_device(monkeypatch):
@@ -337,8 +339,9 @@ def test_rank_positions_count_asked_and_launched(monkeypatch):
         return rank1(level, pos)
 
     monkeypatch.setattr(t.device, "rank1", spy)
-    t.rows_many(rng.integers(0, 300, 200))
-    t.cols_many(rng.integers(0, 500, 300))
+    wide = device_rank.DESCENT_MAX_BATCH + 1  # past the fused descent: per level
+    t.rows_many(rng.integers(0, 300, wide))
+    t.cols_many(rng.integers(0, 500, wide))
     t.row(int(r[0]))  # narrow levels: host ranks
     n = t.rank_positions
     assert batches and n["device", "asked"] == sum(batches)
@@ -348,10 +351,15 @@ def test_rank_positions_count_asked_and_launched(monkeypatch):
     assert t.rank_calls["descent", "device"] == len(batches)
     assert set(n) == {("device", "asked"), ("device", "launched"),
                       ("host", "asked"), ("host", "launched")}
+    t.rows_many(rng.integers(0, 300, 200))  # one fused launch: its entries
+    assert t.rank_calls["descent", "device"] == len(batches) + 1
+    assert 0 < n["fused", "asked"] <= n["fused", "launched"]
 
 
-def test_device_rank_failure_raises(monkeypatch):
-    """A device error surfaces to the caller: no numpy answer stands in."""
+@pytest.mark.parametrize("program,batch", [("rank1", 2048), ("descend", 128)])
+def test_device_rank_failure_raises(monkeypatch, program, batch):
+    """A device error surfaces to the caller, from the per-level rank or
+    the fused descent: no numpy answer stands in."""
     from repro.core.succinct import device_rank
 
     _on_device(monkeypatch)
@@ -362,10 +370,117 @@ def test_device_rank_failure_raises(monkeypatch):
     def broken(*args):
         raise RuntimeError("device lost")
 
-    monkeypatch.setattr(device_rank.DeviceLevels, "rank1", broken)
+    monkeypatch.setattr(device_rank.DeviceLevels, program, broken)
     with pytest.raises(RuntimeError, match="device lost"):
-        t.rows_many(np.arange(128, dtype=np.int64))
+        t.rows_many(np.arange(batch, dtype=np.int64) % 128)
     assert t.rank_calls["descent", "host"] == 0
+
+
+# ---------------- fused descent (one device program over every level) ----------------
+def _host_and_device(monkeypatch, rows, cols, n, m, k=2):
+    host = K2Tree(rows, cols, n, m, k=k)
+    _on_device(monkeypatch)
+    return host, K2Tree(rows, cols, n, m, k=k)
+
+
+def _same_lines(a, b, qs, axis):
+    many = (lambda t: t.rows_many) if axis == 0 else (lambda t: t.cols_many)
+    got, want = many(a)(qs), many(b)(qs)
+    assert all(np.array_equal(x, y) for x, y in zip(got, want))
+    return got
+
+
+@pytest.mark.parametrize("axis", [0, 1])
+@pytest.mark.parametrize("k", [2, 3, 4])
+def test_k2_fused_descent_matches_host(monkeypatch, k, axis):
+    """rows_many / cols_many through the fused program equal the host
+    descent on random sparse matrices, with out-of-range, negative and
+    repeated coordinates in the batch."""
+    rng = np.random.default_rng(100 * k + axis)
+    n, m = 300, 500
+    r, c = _random_matrix(rng, n, m, 0.02)
+    host, dev = _host_and_device(monkeypatch, r, c, n, m, k)
+    lim = n if axis == 0 else m
+    qs = np.concatenate([rng.integers(0, lim, 200), [-1, lim, lim + 7, -50, 3, 3]])
+    idx, _ = _same_lines(dev, host, qs, axis)
+    assert len(idx) > 0
+    assert dev.descent_calls == {"fused": 1}
+    assert host.descent_calls == {"levels": 1}
+
+
+@pytest.mark.parametrize("case", ["empty_tree", "empty_rows", "out_of_range"])
+def test_k2_fused_descent_edge_cases(monkeypatch, case):
+    """An empty tree, a batch of empty rows and a batch with no in-range
+    coordinate answer nothing, exactly as the host does."""
+    rng = np.random.default_rng(8)
+    n, m = 256, 256
+    if case == "empty_tree":
+        r, c = np.zeros(0, np.int64), np.zeros(0, np.int64)
+    else:  # rows 0..127 are empty
+        r, c = rng.integers(128, n, 400), rng.integers(0, m, 400)
+    host, dev = _host_and_device(monkeypatch, r, c, n, m)
+    qs = {"empty_tree": np.arange(64), "empty_rows": np.arange(128),
+          "out_of_range": np.concatenate([np.arange(-40, 0), np.arange(n, n + 40)])}[case]
+    for axis in (0, 1):
+        idx, coords = _same_lines(dev, host, qs, axis)
+        if axis == 0 or case != "empty_rows":
+            assert len(idx) == len(coords) == 0
+    assert dev.descent_calls["fused"] == (2 if case == "empty_rows" else 0)
+
+
+@pytest.mark.parametrize("q", [1, 2, 31, 32])
+def test_k2_fused_descent_threshold(monkeypatch, q):
+    """A single in-range coordinate keeps the per-level walk; from
+    FUSED_MIN_BATCH on, one fused launch answers the batch, below and above
+    DEVICE_MIN_BATCH alike."""
+    from repro.core.succinct.device_rank import DEVICE_MIN_BATCH, FUSED_MIN_BATCH
+
+    assert (FUSED_MIN_BATCH, DEVICE_MIN_BATCH) == (2, 32)
+    rng = np.random.default_rng(q)
+    r, c = _random_matrix(rng, 200, 200, 0.03)
+    host, dev = _host_and_device(monkeypatch, r, c, 200, 200)
+    qs = np.concatenate([rng.integers(0, 200, q), [-1, 500]])  # two out of range
+    _same_lines(dev, host, qs, 0)
+    fused = q >= FUSED_MIN_BATCH
+    assert dev.descent_calls == ({"fused": 1} if fused else {"levels": 1})
+    if fused:
+        assert dev.rank_calls == {("descent", "device"): 1}
+
+
+@pytest.mark.parametrize("axis", [0, 1])
+def test_k2_fused_descent_grows_capacity_and_reruns(monkeypatch, axis):
+    """A batch past its capacities reruns with grown ones and answers
+    exactly; the grown capacities serve the next batch at once."""
+    from repro.core.succinct.device_rank import DESCENT_MAX_BATCH
+
+    rng = np.random.default_rng(21 + axis)
+    n, m = 1000, 1000
+    r, c = _random_matrix(rng, n, m, 0.01)
+    host, dev = _host_and_device(monkeypatch, r, c, n, m)
+    dev.capacities[axis] = (DESCENT_MAX_BATCH, 16)
+    qs = rng.integers(0, n, 500)
+    _same_lines(dev, host, qs, axis)
+    assert dev.descent_calls["fused_retry"] > 0
+    cap, out_cap = dev.capacities[axis]
+    assert cap > DESCENT_MAX_BATCH and 16 < out_cap <= cap
+    before = dev.descent_calls["fused_retry"]
+    _same_lines(dev, host, qs, axis)
+    assert dev.descent_calls["fused_retry"] == before
+
+
+def test_k2_fused_descent_from_levels(monkeypatch):
+    """A tree loaded through from_levels descends fused, as built."""
+    rng = np.random.default_rng(31)
+    n, m = 400, 300
+    r, c = _random_matrix(rng, n, m, 0.02)
+    host, dev = _host_and_device(monkeypatch, r, c, n, m, k=3)
+    loaded = K2Tree.from_levels(n, m, dev.k, dev.h, dev.n_points,
+                                [lv.words for lv in dev.levels],
+                                [lv.n for lv in dev.levels])
+    qs = rng.integers(0, 300, 100)
+    for axis in (0, 1):
+        _same_lines(loaded, host, qs, axis)
+    assert loaded.descent_calls == {"fused": 2}
 
 
 def test_rank_placement_follows_platform(monkeypatch):

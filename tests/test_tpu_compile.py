@@ -1,4 +1,4 @@
-"""Compile the chip's rank path for a described TPU v5e, no chip attached.
+"""Compile the chip's rank and fused descent for a described TPU v5e, no chip attached.
 
 The topology is described inside a fixture, never at import, so every
 pytest-xdist worker collects the same tests and only the worker running
@@ -49,3 +49,18 @@ def test_rank_compiles_for_v5e(one_chip, width):
         assert mem.argument_size_in_bytes >= 8 * width + 4 * bucket
         assert mem.output_size_in_bytes == 4 * bucket
         assert "gather" in compiled.as_text()
+
+
+def test_descent_compiles_for_v5e(one_chip):
+    """The TPU compiler accepts the fused descent over a 2**20-word tree of
+    19 levels (jamendo's deepest) with a 64k-entry frontier; its arguments
+    cover the whole word and rank arrays, and it gathers from them."""
+    from repro.kernels.k2_descent import k2_descent
+
+    width, h = 2**20, 19
+    compiled = k2_descent.lower(*device_rank.descent_args(width, h, one_chip),
+                                k=2, axis=0, cap=65_536, out_cap=4096).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 8 * width + 4 * device_rank.DESCENT_MAX_BATCH
+    assert mem.output_size_in_bytes >= 4 * (h + 2 * 4096)  # sizes, then pairs
+    assert "gather" in compiled.as_text()

@@ -31,7 +31,8 @@ PARENTS = {
     "itr.engine.overlay": {"itr.shard.exec"},
     "itr.k2.descent": {"itr.engine.seed", "itr.engine.scalar"},
     "itr.k2.rank": {"itr.k2.descent"},
-    "itr.k2.compile": {"itr.k2.rank"},
+    "itr.k2.fused": {"itr.k2.descent"},
+    "itr.k2.compile": {"itr.k2.rank", "itr.k2.fused"},
 }
 POOL_SPANS = {"itr.wait.engine", "itr.shard.exec"}
 
@@ -63,6 +64,19 @@ def _patterns(triples, svc):
     busiest = np.bincount(routes).argmax()
     own = t[routes == busiest][:120]
     return [(int(s), int(p), None) for s, p in own] + [(int(t[0, 0]), None, None)]
+
+
+def _wide_patterns(triples, svc):
+    """One `sp?` lookup for each subject the busiest shard holds, at least
+    DEVICE_MIN_BATCH seeds so that shard descends in one fused launch, and
+    an `s??` the first batch did not cache, scattered to every shard."""
+    t = triples[:, :2]
+    routes = svc.plan.route_batch(t[:, 0], t[:, 1], np.full(len(t), -1))
+    own = t[routes == np.bincount(routes).argmax()]
+    _, first = np.unique(own[:, 0], return_index=True)
+    assert len(first) >= device_rank.DEVICE_MIN_BATCH
+    other = int(t[t[:, 0] != t[0, 0], 0][0])
+    return [(int(s), int(p), None) for s, p in own[first]] + [(other, None, None)]
 
 
 def _capture(tmp_path: Path, run):
@@ -122,13 +136,18 @@ def test_no_itr_event_while_off(tmp_path, monkeypatch, tracing_off):
 @pytest.mark.time_limit(120)
 def test_every_span_fires_nested_with_flush_ids(tmp_path, monkeypatch, tracing_off):
     svc, triples = _store(monkeypatch)
-    pats = _patterns(triples, svc)
+    pats, wide = _patterns(triples, svc), _wide_patterns(triples, svc)
     device_rank._program.cache_clear()  # the first device rank compiles
+    device_rank._descent_program.cache_clear()  # and the first fused descent
+    # the first batch's few seeds walk level by level (ranks on both sides),
+    # the wide batch's descend fused
+    monkeypatch.setattr(device_rank, "FUSED_MIN_BATCH", device_rank.DEVICE_MIN_BATCH)
 
     def run():
         tracing.enable(True)
         try:
             svc.query_many(pats)
+            svc.query_many(wide)
             gc.collect()
         finally:
             tracing.enable(False)
@@ -143,8 +162,8 @@ def test_every_span_fires_nested_with_flush_ids(tmp_path, monkeypatch, tracing_o
                for evs in lines for _, _, name, st in evs)
     flushes = {st["flush"] for evs in lines for _, _, name, st in evs
                if name == "itr.flush"}
-    assert len(flushes) == 1
-    ranks = set()
+    assert len(flushes) == 2
+    ranks, fused = set(), []
     for evs in lines:
         for i, (_, _, name, st) in enumerate(evs):
             if name == "itr.gc":
@@ -154,4 +173,8 @@ def test_every_span_fires_nested_with_flush_ids(tmp_path, monkeypatch, tracing_o
                 assert st["flush"] in flushes and "shard" in st, name
             if name == "itr.k2.rank":
                 ranks.add(st["side"])
+            if name == "itr.k2.fused":
+                fused.append(st)
     assert ranks == {"device", "host"}
+    assert fused and all(st["queries"] >= device_rank.DEVICE_MIN_BATCH
+                         and st["capacity"] > 0 for st in fused)
